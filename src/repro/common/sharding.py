@@ -37,22 +37,9 @@ def _expand(entry):
 
 
 def ambient_mesh():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and m.axis_names:
-            return m
-    except Exception:
-        pass
-    # Older JAX (no get_abstract_mesh / jax.set_mesh): ``with mesh:`` sets
-    # the thread-resources physical mesh instead.
-    try:
-        from jax.interpreters import pxla
-        m = pxla.thread_resources.env.physical_mesh
-        if m is not None and not m.empty and m.axis_names:
-            return m
-    except Exception:
-        pass
-    return None
+    """The mesh ``jax.set_mesh`` installed, or None outside one."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def _filter_entry(entry, dim: int, axis_sizes: dict[str, int]):
@@ -90,8 +77,4 @@ def shard_hint(x, *entries):
     mesh = ambient_mesh()
     if mesh is None:
         return x
-    spec = logical_spec(x.shape, entries)
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, logical_spec(x.shape, entries))

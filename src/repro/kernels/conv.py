@@ -22,8 +22,9 @@ pre-quantize HBM pass.
 
 **im2col fallback** (``w4a4_conv2d_im2col``): unfolds x into the patch
 matrix and feeds the fused W4A4 matmul. Kept as the oracle for the
-implicit route's index maps and as the fallback when the implicit
-kernel's VMEM footprint (whole-slab blocks) exceeds budget.
+implicit route's index maps and as the compiled route for what the
+implicit kernel cannot take (``implicit_supported``): strided convs, and
+convs whose whole-slab blocks exceed the VMEM budget.
 
 Zero-padding correctness (im2col route): SAME padding inserts exact
 zeros into the patch matrix. A *signed* MSFP snap maps 0 -> 0, so fusing
@@ -45,7 +46,8 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.qmodule import PackedW4
 from repro.kernels.msfp_quant import _qdq_block
 from repro.kernels.w4_matmul import (_decode_block, _split_half_rows,
-                                     w4_matmul_2d, w4a4_matmul_2d)
+                                     lane_tile, mxu_dot, w4_matmul_2d,
+                                     w4a4_matmul_2d)
 from repro.quant.fakequant import KIND_FP_SIGNED, QuantizerParams
 from repro.quant.formats import FPFormat
 
@@ -118,10 +120,11 @@ def w4a4_conv2d_im2col(x: jnp.ndarray, pw: PackedW4,
 # Implicit GEMM: the unfold lives in the BlockSpec index maps.
 # ---------------------------------------------------------------------------
 
-# Per-program VMEM footprint cap for the implicit route (slab + snap-once
-# scratch + packed block + accumulator). Above this the dispatcher falls
-# back to the im2col route.
-IMPLICIT_VMEM_BUDGET = 8 * 1024 * 1024
+# The scoped VMEM a Pallas kernel may use on TPU v5e unless it asks for
+# more (found by compiling for v5e: a kernel that needs 13.2 MiB compiles
+# without a limit, one that needs 17.9 MiB is refused). The implicit route
+# takes a conv only while ``implicit_vmem_bytes`` stays within it.
+IMPLICIT_VMEM_BUDGET = 16 * 1024 * 1024
 
 
 def _conv_geometry(x_shape, kh, kw, stride, padding):
@@ -145,18 +148,49 @@ def _conv_geometry(x_shape, kh, kw, stride, padding):
 def implicit_vmem_bytes(x_shape, pw_shape, stride, padding, *,
                         fused: bool, itemsize: int = 4,
                         bc: int = 128, bn: int = 128) -> int:
-    """Worst-case per-program VMEM bytes for ``w4a4_conv2d_implicit``."""
+    """Per-program VMEM bytes of ``w4a4_conv2d_implicit`` compiled for TPU.
+
+    Counts what Mosaic holds at once: the input slab, packed-weight and
+    output blocks, each double-buffered, with the slab's W dim padded to 8
+    sublanes; the accumulator and snap-once scratch; and the body's
+    temporaries — the decoded weight block (int32 codes and f32 values),
+    the f32 tap accumulator, two tap windows, the snapped slab (two
+    copies, four once cin spans several blocks), and for f32 operands
+    what the f32-precision tap dots add (5.2-6.0 KiB per output row at
+    the UNet widths). ``tools/vmem_fit.py`` prints it beside the smallest
+    scoped-VMEM limit the v5e compiler accepts; it must never be below.
+    """
     kh, kw, cin, cout = pw_shape
     oh, ow, hs, ws, _, _ = _conv_geometry(x_shape, kh, kw, stride, padding)
     bc = min(bc, cin)
-    bn = min(bn, max(cout // 2, 1))
+    bn = lane_tile(max(cout // 2, 1), bn, interpret=False)
     cin_p = cin + (-cin) % bc
+    ws8 = ws + (-ws) % 8
     mp = oh * ow + (-(oh * ow)) % 8
-    slab = hs * ws * bc * itemsize
-    xq = hs * ws * cin_p * itemsize if fused else 0
-    packed = kh * kw * bc * bn
-    acc = mp * bn * 4
-    return slab + xq + packed + acc
+    slab = hs * ws8 * bc * itemsize
+    blocks = 2 * (slab + kh * kw * bc * bn + mp * bn * itemsize)
+    scratch = mp * bn * 4 + (hs * ws8 * cin_p * itemsize if fused else 0)
+    temps = 2 * kh * kw * bc * bn * 4 + mp * bn * 4 + 2 * mp * bc * itemsize
+    if fused:
+        temps += (2 if cin_p == bc else 4) * hs * ws8 * bc * 4
+    if itemsize == 4:
+        temps += 12 * mp * bn * 4      # bf16 splits and partial products
+    return blocks + scratch + temps
+
+
+def implicit_supported(x_shape, pw_shape, stride, padding, *, fused: bool,
+                       itemsize: int = 4) -> bool:
+    """Whether the compiled implicit kernel can take this conv.
+
+    Unit stride only: Mosaic lowers a strided in-VMEM tap slice to a
+    gather and refuses it ("Only 2D gather is supported"). And the
+    per-program footprint must fit ``IMPLICIT_VMEM_BUDGET``.
+    """
+    if tuple(stride) != (1, 1):
+        return False
+    return implicit_vmem_bytes(x_shape, pw_shape, stride, padding,
+                               fused=fused, itemsize=itemsize
+                               ) <= IMPLICIT_VMEM_BUDGET
 
 
 def _implicit_kernel(x_ref, p_ref, s_ref, z_ref, amz_ref, o_ref, acc_ref,
@@ -212,8 +246,7 @@ def _implicit_kernel(x_ref, p_ref, s_ref, z_ref, amz_ref, o_ref, acc_ref,
             xv = slab[ki:ki + sh * (oh - 1) + 1:sh,
                       kj:kj + sw * (ow - 1) + 1:sw, :]
             xv = xv.reshape(oh * ow, xv.shape[-1])
-            acc += jnp.dot(xv, wt[ki * kw + kj],
-                           preferred_element_type=jnp.float32)
+            acc += mxu_dot(xv, wt[ki * kw + kj])
             if not fmt.signed:
                 rowsum = jnp.sum(xv.astype(jnp.float32), axis=1,
                                  keepdims=True)
@@ -258,8 +291,8 @@ def w4a4_conv2d_implicit(x: jnp.ndarray, pw: PackedW4,
     xp = xp[:, :hs, :ws, :]
 
     n_half = cout // 2
-    pn = (-n_half) % min(bn, n_half)
-    bn = min(bn, n_half)
+    bn = lane_tile(n_half, bn, interpret=interpret)
+    pn = (-n_half) % bn
     nj = (n_half + pn) // bn
     packed3 = pw.packed.reshape(kh * kw, cin, n_half)
     if pc or pn:
@@ -298,8 +331,8 @@ def w4a4_conv2d_implicit(x: jnp.ndarray, pw: PackedW4,
         in_specs=[
             pl.BlockSpec((1, hs, ws, bc), lambda bi, hh, j, c: (bi, 0, 0, c)),
             pl.BlockSpec((kh * kw, bc, bn), lambda bi, hh, j, c: (0, c, j)),
-            pl.BlockSpec((1, bn), lambda bi, hh, j, c: (hh, j)),
-            pl.BlockSpec((1, bn), lambda bi, hh, j, c: (hh, j)),
+            pl.BlockSpec((None, 1, bn), lambda bi, hh, j, c: (hh, 0, j)),
+            pl.BlockSpec((None, 1, bn), lambda bi, hh, j, c: (hh, 0, j)),
             pl.BlockSpec((1, 2), lambda bi, hh, j, c: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, mp, bn),

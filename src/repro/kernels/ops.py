@@ -1,18 +1,20 @@
 """Jit'd wrappers around the Pallas kernels with XLA fallbacks.
 
-Dispatch policy: on TPU the Pallas kernels run compiled; on CPU (this
-container) the fast XLA serving path (``kernels.xla_serve``) runs for
-real numerics, while tests exercise the kernels in interpret mode against
-the ref oracles. Set ``FORCE="pallas"`` / ``"xla"`` / ``"interpret"`` to
-override (tests use it) — ``"xla"`` pins the *pure reference* oracles,
-bypassing the fast serving path too.
+Dispatch policy: on TPU the Pallas kernels run compiled; off the TPU the
+fast XLA serving path (``kernels.xla_serve``) runs for real numerics,
+while tests exercise the kernels in interpret mode against the ref
+oracles. Set ``FORCE="pallas"`` / ``"xla"`` / ``"interpret"`` to override
+(tests use it) — ``"xla"`` pins the *pure reference* oracles, bypassing
+the fast serving path too. Interpret mode runs only when asked for:
+``FORCE="pallas"`` off the TPU raises rather than interpreting.
 
 Conv routing (``CONV_ROUTE``): the Pallas conv has two routes — the
-implicit-GEMM kernel (no patch matrix; the default on compiled TPU when
-its whole-slab blocks fit VMEM) and the im2col + fused-matmul route (the
-index-map oracle, and what interpret mode runs by default so the golden
-replay trace keeps its pinned digest). ``"implicit"`` / ``"im2col"``
-force a route; ``"auto"`` applies the policy above.
+implicit-GEMM kernel (no patch matrix; the default on compiled TPU for
+the convs it can compile, see ``conv.implicit_supported``) and the im2col
++ fused-matmul route (the index-map oracle, and what interpret mode runs
+by default so the golden replay trace keeps its pinned digest).
+``"implicit"`` / ``"im2col"`` force a route; ``"auto"`` applies the
+policy above.
 """
 from __future__ import annotations
 
@@ -54,7 +56,16 @@ def _use_pallas() -> bool:
 
 
 def _interpret() -> bool:
-    return FORCE == "interpret" or jax.default_backend() != "tpu"
+    """Whether a Pallas branch runs interpreted; only called once
+    ``_use_pallas()`` chose one."""
+    if FORCE == "interpret":
+        return True
+    if jax.default_backend() == "tpu":
+        return False
+    raise RuntimeError(
+        f"ops.FORCE={FORCE!r} compiles the Pallas kernels, which needs a "
+        f"TPU, but jax.default_backend() is {jax.default_backend()!r}; "
+        "set FORCE='interpret' to run the kernel code interpreted")
 
 
 def _use_fast_xla() -> bool:
@@ -172,20 +183,20 @@ def _normalize_padding(padding):
     return tuple(tuple(int(q) for q in p) for p in padding)
 
 
-def _conv_route(x, pw, strides, pads, fused: bool) -> str:
+def _conv_route(x, pw, strides, pads, fused: bool, interpret: bool) -> str:
     """Pick the Pallas conv route. ``auto``: compiled TPU runs the
-    implicit-GEMM kernel when its whole-slab blocks fit the VMEM budget;
+    implicit-GEMM kernel for every conv it can compile (unit stride,
+    whole-slab blocks within the VMEM budget) and im2col otherwise;
     interpret mode keeps the im2col oracle route (the golden replay
     trace's digest is pinned to its accumulation order)."""
     if CONV_ROUTE in ("implicit", "im2col"):
         return CONV_ROUTE
-    if _interpret():
+    if interpret:
         return "im2col"
-    from repro.kernels.conv import IMPLICIT_VMEM_BUDGET, implicit_vmem_bytes
-    fits = implicit_vmem_bytes(
-        x.shape, pw.shape, strides, pads, fused=fused,
-        itemsize=x.dtype.itemsize) <= IMPLICIT_VMEM_BUDGET
-    return "implicit" if fits else "im2col"
+    from repro.kernels.conv import implicit_supported
+    ok = implicit_supported(x.shape, pw.shape, strides, pads, fused=fused,
+                            itemsize=x.dtype.itemsize)
+    return "implicit" if ok else "im2col"
 
 
 def w4a4_conv2d(x: jnp.ndarray, pw: PackedW4,
@@ -210,7 +221,9 @@ def w4a4_conv2d(x: jnp.ndarray, pw: PackedW4,
     strides = _normalize_stride(stride)
     pads = _normalize_padding(padding)
     if _use_pallas() and len(pw.shape) == 4 and _pallas_w4_ok(pw):
-        route = _conv_route(x, pw, strides, pads, fused=act_qp is not None)
+        interpret = _interpret()
+        route = _conv_route(x, pw, strides, pads, fused=act_qp is not None,
+                            interpret=interpret)
         fusable = (KIND_FP_SIGNED, KIND_FP_UNSIGNED) if route == "implicit" \
             else (KIND_FP_SIGNED,)
         if act_qp is not None and not (act_qp.kind in fusable
@@ -223,13 +236,13 @@ def w4a4_conv2d(x: jnp.ndarray, pw: PackedW4,
                 "w4a4_conv2d", f"{_route_label()}:implicit",
                 lambda: w4a4_conv2d_implicit(x, pw, act_qp, stride=strides,
                                              padding=pads,
-                                             interpret=_interpret()),
+                                             interpret=interpret),
                 probe=x)
         from repro.kernels.conv import w4a4_conv2d_im2col
         return _dispatch(
             "w4a4_conv2d", f"{_route_label()}:im2col",
             lambda: w4a4_conv2d_im2col(x, pw, act_qp, stride=strides,
-                                       padding=pads, interpret=_interpret()),
+                                       padding=pads, interpret=interpret),
             probe=x)
     fast = _use_fast_xla() and len(pw.shape) == 4 and _pallas_w4_ok(pw)
     fusable = (KIND_FP_SIGNED, KIND_FP_UNSIGNED) if fast \

@@ -1,4 +1,9 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose ground truth)."""
+"""Pure-jnp oracles for every Pallas kernel (the allclose ground truth).
+
+Their dots and convs run at f32 precision (HIGHEST) on every backend, so
+on the TPU they are the f32 truth that the kernels are held to, whatever
+the ambient default matmul precision.
+"""
 from __future__ import annotations
 
 import jax.numpy as jnp
@@ -22,7 +27,8 @@ def ref_w4_matmul(x: jnp.ndarray, pw: PackedW4,
     """Oracle for the packed-W4 matmul kernel: decode then dot."""
     codes = unpack_nibbles(pw.packed)
     w = decode_codes(codes, pw.fmt, pw.scale, pw.zero_point, jnp.float32)
-    return (x.astype(jnp.float32) @ w).astype(dtype)
+    y = jnp.dot(x.astype(jnp.float32), w, precision=lax.Precision.HIGHEST)
+    return y.astype(dtype)
 
 
 def ref_w4a4_matmul(x: jnp.ndarray, pw: PackedW4, act_qp: QuantizerParams,
@@ -46,7 +52,8 @@ def ref_w4a4_conv2d(x: jnp.ndarray, pw: PackedW4,
     w = dequant_weight(pw, jnp.float32)   # reshaped back to HWIO
     y = lax.conv_general_dilated(
         x.astype(jnp.float32), w, window_strides=stride, padding=padding,
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=lax.Precision.HIGHEST)
     return y.astype(dtype)
 
 

@@ -18,9 +18,18 @@ Covered format space (the full MSFP family):
 Grid: (M/bm, half, (N/2)/bn, K/bk) — the `half` axis selects the nibble
 and addresses the corresponding output column block, so no lane interleave
 is ever needed. K is the innermost (arbitrary) axis accumulating into an
-f32 VMEM scratch. Scales/zero-points ride as a (2, N/2) operand blocked
-(1, bn) and indexed by the (half, j) grid axes, so each program sees
-exactly the scales of the columns it decodes.
+f32 VMEM scratch. Scales/zero-points ride as a (2, 1, N/2) operand whose
+leading (half) block dim is squeezed, blocked (1, bn) and indexed by the
+(half, j) grid axes, so each program sees exactly the scales of the
+columns it decodes.
+
+TPU tiling: Mosaic needs the last two block dims divisible by (8, 128) or
+equal to the array's, so the column tile ``bn`` is always a multiple of
+128 lanes. A half narrower than that (N/2 = 64 at the 128-channel layers)
+is zero-padded up to one lane tile; the MXU is 128 wide, so the padded
+dot costs no more passes than the narrow one would. Interpret mode has no
+such rule and keeps the narrow tile: the CPU dot rounds differently at
+another width, and the golden replay digest is pinned to the narrow one.
 
 Snap-once re-tiling: with M outermost, every (half, j) program for a fixed
 row block i revisits the same x tiles, so the fused path snaps each
@@ -68,6 +77,19 @@ def _decode_block(codes, fmt: FPFormat, scale):
     if fmt.signed:
         val = jnp.where(sign == 1, -val, val)
     return val
+
+
+def mxu_dot(x, w):
+    """``x @ w`` accumulated in f32, at the operands' own precision.
+
+    On the TPU a dot of f32 operands at the default precision takes one
+    bf16 MXU pass, rounding x and the scaled weight to bf16; HIGHEST keeps
+    the f32 the model computes in (Mosaic refuses it for bf16 operands,
+    which need no more than the one pass). The CPU computes f32 dots at
+    f32 either way, so interpret-mode outputs do not move.
+    """
+    prec = jax.lax.Precision.HIGHEST if x.dtype == jnp.float32 else None
+    return jnp.dot(x, w, precision=prec, preferred_element_type=jnp.float32)
 
 
 # Fused-path activation scratch cap: above this the snap-once (bm, K)
@@ -122,7 +144,7 @@ def _kernel(x_ref, p_ref, s_ref, z_ref, amz_ref, o_ref, acc_ref, *xq_ref,
     codes = (p_ref[...].astype(jnp.int32) >> shift) & 0xF
     scale = s_ref[0, :] * (1.0 / fmt.base_max)          # (bn,) per-channel
     w = _decode_block(codes, fmt, scale[None, :]).astype(x.dtype)
-    acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
+    acc_ref[...] += mxu_dot(x, w)
     if not fmt.signed:
         # zp contributes zp_n * sum_k x_ik; accumulate the block's rowsum.
         rowsum = jnp.sum(x.astype(jnp.float32), axis=1, keepdims=True)
@@ -133,12 +155,25 @@ def _kernel(x_ref, p_ref, s_ref, z_ref, amz_ref, o_ref, acc_ref, *xq_ref,
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
+LANES = 128
+
+
+def lane_tile(n_half: int, bn: int = LANES, *, interpret: bool) -> int:
+    """Column tile for one nibble half. Compiled: ``bn`` capped at the
+    half's width rounded up to whole lane tiles (a multiple of 128).
+    Interpret: ``bn`` capped at the half's own width."""
+    if interpret:
+        return min(bn, n_half)
+    return min(bn, n_half + (-n_half) % LANES)
+
+
 def _split_half_rows(vec: jnp.ndarray, n_half: int, pad: int) -> jnp.ndarray:
-    """(N,) channel vector -> (2, N/2 [+pad]) rows matching the nibble halves."""
+    """(N,) channel vector -> (2, 1, N/2 [+pad]) rows matching the nibble
+    halves; the unit middle dim makes a (1, bn) block tile-legal."""
     op = jnp.stack([vec[:n_half], vec[n_half:]])
     if pad:
         op = jnp.pad(op, ((0, 0), (0, pad)))
-    return op
+    return op[:, None, :]
 
 
 def _w4_call(x, packed, scale, zero_point, act_mz, *, fmt: FPFormat,
@@ -149,7 +184,7 @@ def _w4_call(x, packed, scale, zero_point, act_mz, *, fmt: FPFormat,
     assert k == k2, (x.shape, packed.shape)
     n = 2 * n_half
     bm = min(bm, m)
-    bn = min(bn, n_half)
+    bn = lane_tile(n_half, bn, interpret=interpret)
     bk = min(bk, k)
     pm, pk, pn = (-m) % bm, (-k) % bk, (-n_half) % bn
     if pm or pk:
@@ -186,8 +221,8 @@ def _w4_call(x, packed, scale, zero_point, act_mz, *, fmt: FPFormat,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, h, j, kb: (i, kb)),
             pl.BlockSpec((bk, bn), lambda i, h, j, kb: (kb, j)),
-            pl.BlockSpec((1, bn), lambda i, h, j, kb: (h, j)),
-            pl.BlockSpec((1, bn), lambda i, h, j, kb: (h, j)),
+            pl.BlockSpec((None, 1, bn), lambda i, h, j, kb: (h, 0, j)),
+            pl.BlockSpec((None, 1, bn), lambda i, h, j, kb: (h, 0, j)),
             pl.BlockSpec((1, 2), lambda i, h, j, kb: (0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn),
@@ -208,11 +243,12 @@ def _w4_call(x, packed, scale, zero_point, act_mz, *, fmt: FPFormat,
 
 def pick_tiles(m: int, k: int, n: int, *, bm: int = 128, bn: int = 128,
                bk: int = 512) -> dict:
-    """The (clamped) tile sizes ``_w4_call`` uses at this shape.
+    """The (clamped) tile sizes the compiled ``_w4_call`` uses at this shape.
 
     The bench records these per row so wall-clock numbers stay comparable
     across PRs that change the tiling."""
-    return {"bm": min(bm, m), "bn": min(bn, n // 2), "bk": min(bk, k)}
+    return {"bm": min(bm, m), "bn": lane_tile(n // 2, bn, interpret=False),
+            "bk": min(bk, k)}
 
 
 @functools.partial(jax.jit, static_argnames=("exp_bits", "man_bits", "signed",

@@ -1,5 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# The 512 devices are host (CPU) devices; pinning the platform keeps the
+# dry-run off any attached TPU, which belongs to one process at a time.
+os.environ["JAX_PLATFORMS"] = "cpu"
 # (required before ANY jax import — jax locks device count on first init.
 #  REPRO_DRYRUN_DEVICES overrides for quick local runs, e.g. 64.)
 if os.environ.get("REPRO_DRYRUN_DEVICES"):
@@ -33,10 +36,10 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.common.clock import wall_clock
+from repro.common.compile_cache import setup_compile_cache
 from repro.configs.registry import ARCH_IDS, all_cells, get_config
 from repro.configs.shapes import SHAPES
-from repro.launch.mesh import (make_production_mesh, mesh_chip_count,
-                               mesh_scope)
+from repro.launch.mesh import make_production_mesh, mesh_chip_count
 from repro.launch.sharding import (cache_shardings, data_spec,
                                    param_shardings)
 from repro.launch.steps import (abstract_caches, abstract_opt,
@@ -130,7 +133,7 @@ def _compile_cell(cfg, shape, mesh, *, quant: str, kv: str, big: bool,
     set_dp_axes(batch_axes)  # activation hints must match input shardings
     rec: dict = {}
     t0 = wall_clock()
-    with mesh_scope(mesh):
+    with jax.set_mesh(mesh):
         aparams = abstract_params(cfg)
         if quant == "w4" and shape.kind != "train":
             aparams = quantize_abstract(aparams)
@@ -277,6 +280,7 @@ def main() -> None:
                          "(pass/fail + memory only — multi-pod sweep)")
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
+    setup_compile_cache()
     os.makedirs(args.out, exist_ok=True)
 
     cells = all_cells()

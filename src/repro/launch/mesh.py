@@ -1,4 +1,4 @@
-"""Production meshes + version-compat shims for the mesh API.
+"""Production meshes.
 
 Single pod:  (16, 16)      ("data", "model")   = 256 chips (v5e pod)
 Multi-pod:   (2, 16, 16)   ("pod", "data", "model") = 512 chips
@@ -11,45 +11,19 @@ Defined as functions (not module constants) so importing this module never
 touches jax device state — the dry-run must set
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before first init.
 
-Compat: newer JAX exposes ``jax.sharding.AxisType`` + ``jax.set_mesh``;
-older releases (e.g. 0.4.x in this container) have neither, but ``Mesh``
-itself is a context manager that sets the ambient mesh. ``compat_make_mesh``
-and ``mesh_scope`` paper over the difference so launchers and tests run on
-either API.
+Axes are ``Auto``: GSPMD propagates shardings from the inputs and the
+models' ``shard_hint`` constraints. Install one with ``jax.set_mesh``.
 """
 from __future__ import annotations
 
 import jax
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
-def compat_make_mesh(shape: tuple, axes: tuple):
-    """jax.make_mesh with Auto axis types where the installed JAX has them."""
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
-
-
-def mesh_scope(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` on new JAX; on older releases the ``Mesh`` object's own
-    context manager provides the same scoping for shard_map /
-    with_sharding_constraint.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1):
@@ -57,7 +31,8 @@ def make_host_mesh(model: int = 1):
     n = len(jax.devices())
     model = min(model, n)
     data = n // model
-    return compat_make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_chip_count(mesh) -> int:

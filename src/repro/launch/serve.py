@@ -16,8 +16,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.common.clock import wall_clock
+from repro.common.compile_cache import setup_compile_cache
 from repro.configs.registry import get_config
-from repro.launch.mesh import make_host_mesh, mesh_scope
+from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_decode_fn, quantize_lm_for_serving
 from repro.models.lm import forward, init_caches, lm_init
 from repro.quant.calibrate import QuantContext
@@ -43,13 +44,14 @@ def main(argv=None) -> None:
                          "(deployment default; calibration would refine it)")
     ap.add_argument("--greedy", action="store_true", default=True)
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     cfg = dataclasses.replace(cfg, kv_dtype=args.kv)
     mesh = make_host_mesh()
     s_max = args.prompt_len + args.gen_len
 
-    with mesh_scope(mesh):
+    with jax.set_mesh(mesh):
         key = jax.random.PRNGKey(0)
         params = lm_init(key, cfg)
         if args.quant in ("w4", "w4pc"):

@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.common.clock import wall_clock
+from repro.common.compile_cache import setup_compile_cache
 from repro.configs.diffusion_presets import DIFFUSION_PRESETS, tiny_ddim
 from repro.core import talora
 from repro.diffusion.schedule import make_schedule
@@ -61,6 +62,59 @@ from repro.serving.obs import NULL_OBS, Observability
 from repro.serving.traffic import (MetricsCollector, Scenario, TraceWriter,
                                    get_scenario, list_scenarios, load_trace,
                                    run_scenario)
+
+
+# TALoRA shaping of the diffusion launchers. The gateway and fleet
+# launchers build with it too, so a one-model gateway or one-replica fleet
+# replays this launcher's golden digest.
+SERVE_TALORA = talora.TALoRAConfig(hub_size=2, rank=4, t_emb_dim=32,
+                                   router_hidden=16)
+
+
+def fp4_act_qps(maxval: float = 6.0) -> dict:
+    """Per-tensor signed E2M1 act quant at every site (``--act-quant fp4``)."""
+    return {"*": QuantizerParams(KIND_FP_SIGNED, 2, 1, 4,
+                                 jnp.float32(maxval))}
+
+
+def build_bank(cfg, T: int, *, seed: int, plan_mode: str = "absmax",
+               bank_cap: int = 4):
+    """(sched, q_params, plan, bank): params made from ``seed``, quantized
+    under ``plan_mode``, behind a TALoRA weight bank over a T-step linear
+    schedule."""
+    sched = make_schedule("linear", T)
+    q_params, plan, hubs, router = build_quantized(
+        cfg, sched, jax.random.PRNGKey(seed), plan_mode=plan_mode,
+        talora_cfg=SERVE_TALORA)
+    bank = WeightBank(q_params, plan, hubs, router, SERVE_TALORA, T,
+                      max_cached=bank_cap)
+    return sched, q_params, plan, bank
+
+
+def assert_finite_x0(results) -> None:
+    """Every request that ran must have produced a finite sample."""
+    for rs in results.values():
+        if not rs.expired and not bool(jnp.isfinite(rs.x0).all()):
+            raise AssertionError(f"non-finite x0 rid={rs.req.rid}")
+
+
+def check_conv_sites(q_params, bank, plan_mode: str) -> tuple[int, int]:
+    """(packed, total) conv weight sites. Under the absmax plan every
+    even-width non-io conv must serve packed through the W4A4 conv routes,
+    never from the bf16 fallback bucket."""
+    from repro.common.tree import flatten_paths
+    flat_q = dict(flatten_paths(q_params))
+    conv_w = [k for k, v in flat_q.items()
+              if k.endswith("/w") and getattr(v, "ndim", 0) == 4]
+    packed_sites = set(bank.pack_stats["packed"])
+    if plan_mode == "absmax":
+        missing = [k for k in conv_w
+                   if k not in io_sites(q_params)
+                   and flat_q[k].shape[-1] % 2 == 0
+                   and k not in packed_sites]
+        if missing:
+            raise AssertionError(f"conv sites fell back to bf16: {missing}")
+    return sum(k in packed_sites for k in conv_w), len(conv_w)
 
 
 def build_quantized(cfg, sched, key, *, plan_mode: str, talora_cfg):
@@ -219,6 +273,7 @@ def main(argv=None) -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny everything (CI: 2 concurrent requests)")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     if args.kernels != "auto":
         ops.FORCE = args.kernels
@@ -238,20 +293,15 @@ def main(argv=None) -> None:
         cfg = tiny_ddim(args.image_size)
     else:
         cfg = DIFFUSION_PRESETS[args.preset]()
-    sched = make_schedule("linear", args.T)
-    key = jax.random.PRNGKey(args.seed)
-    tcfg = talora.TALoRAConfig(hub_size=2, rank=4, t_emb_dim=32,
-                               router_hidden=16)
 
     t0 = wall_clock()
-    q_params, plan, hubs, router = build_quantized(
-        cfg, sched, key, plan_mode=args.plan, talora_cfg=tcfg)
-    bank = WeightBank(q_params, plan, hubs, router, tcfg, args.T,
-                      max_cached=args.bank_cap)
+    sched, q_params, plan, bank = build_bank(
+        cfg, args.T, seed=args.seed, plan_mode=args.plan,
+        bank_cap=args.bank_cap)
     act_qps = act_qps_from_plan(plan) if args.plan == "search" else {}
     if args.act_quant == "fp4":
-        act_qps.setdefault("*", QuantizerParams(
-            KIND_FP_SIGNED, 2, 1, 4, jnp.float32(args.act_maxval)))
+        for site, qp in fp4_act_qps(args.act_maxval).items():
+            act_qps.setdefault(site, qp)
     elif args.act_quant == "off":
         act_qps = {}
     clock = VirtualClock() if args.replay_clock == "virtual" else None
@@ -282,10 +332,7 @@ def main(argv=None) -> None:
         writer.close()
         print(f"captured {writer.n} requests -> {args.save_trace}")
     results = engine.results
-    for rs in results.values():
-        if not rs.expired:
-            assert bool(jnp.isfinite(rs.x0).all()), \
-                f"non-finite x0 rid={rs.req.rid}"
+    assert_finite_x0(results)
 
     s = engine.stats()
     evals = sum(rs.n_evals for rs in results.values())
@@ -326,21 +373,8 @@ def main(argv=None) -> None:
           f"(buckets {s['buckets']}), {s['padded_samples']} padded samples, "
           f"{s['idle_sleeps']} idle sleeps")
 
-    # conv parity: every even-width non-io conv weight must serve packed
-    # (the packed W4A4 conv routes), never from the bf16 fallback bucket.
-    from repro.common.tree import flatten_paths
-    flat_q = dict(flatten_paths(q_params))
-    conv_w = [k for k, v in flat_q.items()
-              if k.endswith("/w") and getattr(v, "ndim", 0) == 4]
-    packed_sites = set(bank.pack_stats["packed"])
-    n_conv_packed = sum(k in packed_sites for k in conv_w)
-    print(f"conv sites: {n_conv_packed}/{len(conv_w)} packed (W4A4 conv route)")
-    if args.plan == "absmax":
-        missing = [k for k in conv_w
-                   if k not in io_sites(q_params)
-                   and flat_q[k].shape[-1] % 2 == 0
-                   and k not in packed_sites]
-        assert not missing, f"conv sites fell back to bf16: {missing}"
+    n_conv_packed, n_conv = check_conv_sites(q_params, bank, args.plan)
+    print(f"conv sites: {n_conv_packed}/{n_conv} packed (W4A4 conv route)")
     digest = outcome_digest(results)
     print(f"outcome digest: {digest} "
           f"({len(results)} requests, {summary['expired']} expired)")

@@ -38,13 +38,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.common.clock import wall_clock
+from repro.common.compile_cache import setup_compile_cache
 from repro.configs.diffusion_presets import tiny_ddim
-from repro.core import talora
 from repro.diffusion.schedule import make_schedule
 from repro.kernels import ops
-from repro.launch.serve_diffusion import (_scenario_from_args,
-                                          build_quantized, outcome_digest)
-from repro.quant.fakequant import KIND_FP_SIGNED, QuantizerParams
+from repro.launch.serve_diffusion import (SERVE_TALORA, _scenario_from_args,
+                                          build_quantized, fp4_act_qps,
+                                          outcome_digest)
 from repro.serving import DiffusionServingEngine, VirtualClock, WeightBank
 from repro.serving.fleet import PLACEMENTS, FleetRouter
 from repro.serving.obs import NULL_OBS, Observability
@@ -61,12 +61,10 @@ def build_fleet(args, obs=NULL_OBS):
     cfg = tiny_ddim(args.image_size)
     sched = make_schedule("linear", args.T)
     key = jax.random.PRNGKey(args.seed)
-    tcfg = talora.TALoRAConfig(hub_size=2, rank=4, t_emb_dim=32,
-                               router_hidden=16)
+    tcfg = SERVE_TALORA
     q_params, plan, hubs, router = build_quantized(
         cfg, sched, key, plan_mode="absmax", talora_cfg=tcfg)
-    act_qps = {"*": QuantizerParams(KIND_FP_SIGNED, 2, 1, 4,
-                                    jnp.float32(6.0))}
+    act_qps = fp4_act_qps()
 
     placement = PLACEMENT_ALIASES[args.placement]
     sims: list[SimClock] = []
@@ -154,6 +152,7 @@ def main(argv=None) -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny everything (CI shaping)")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     if args.replicas < 1:
         raise SystemExit("--replicas must be >= 1")

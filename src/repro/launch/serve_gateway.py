@@ -36,16 +36,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.common.clock import wall_clock
+from repro.common.compile_cache import setup_compile_cache
 from repro.configs.diffusion_presets import DIFFUSION_PRESETS, tiny_ddim
 from repro.configs.registry import ARCHS
-from repro.core import talora
-from repro.diffusion.schedule import make_schedule
 from repro.kernels import ops
-from repro.launch.serve_diffusion import (_scenario_from_args,
-                                          build_quantized, outcome_digest)
+from repro.launch.serve_diffusion import (_scenario_from_args, build_bank,
+                                          fp4_act_qps, outcome_digest)
 from repro.launch.steps import quantize_lm_for_serving
 from repro.models.lm import lm_init
-from repro.quant.fakequant import KIND_FP_SIGNED, QuantizerParams
 from repro.serving import DiffusionServingEngine, VirtualClock, WeightBank
 from repro.serving.gateway import (LMServingEngine, ModelRegistry,
                                    ServingGateway, default_entries)
@@ -64,17 +62,9 @@ def build_diffusion_engine(entry, args, eng_kw, obs, max_batch):
         cfg = tiny_ddim(args.image_size)
     else:
         cfg = DIFFUSION_PRESETS[entry.config]()
-    sched = make_schedule("linear", args.T)
-    key = jax.random.PRNGKey(args.seed)
-    tcfg = talora.TALoRAConfig(hub_size=2, rank=4, t_emb_dim=32,
-                               router_hidden=16)
-    q_params, plan, hubs, router = build_quantized(
-        cfg, sched, key, plan_mode="absmax", talora_cfg=tcfg)
-    bank = WeightBank(q_params, plan, hubs, router, tcfg, args.T,
-                      max_cached=args.bank_cap or entry.bank_cap)
-    act_qps = {"*": QuantizerParams(KIND_FP_SIGNED, 2, 1, 4,
-                                    jnp.float32(6.0))}
-    return DiffusionServingEngine(cfg, sched, bank, act_qps=act_qps,
+    sched, _, _, bank = build_bank(cfg, args.T, seed=args.seed,
+                                   bank_cap=args.bank_cap or entry.bank_cap)
+    return DiffusionServingEngine(cfg, sched, bank, act_qps=fp4_act_qps(),
                                   max_batch=max_batch, policy=args.policy,
                                   obs=obs, model=entry.name, **eng_kw)
 
@@ -173,6 +163,7 @@ def main(argv=None) -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny everything (CI shaping)")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     if args.kernels != "auto":
         ops.FORCE = args.kernels

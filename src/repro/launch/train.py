@@ -17,10 +17,10 @@ import numpy as np
 from jax.sharding import NamedSharding
 
 from repro.checkpoint.ckpt import CheckpointManager
+from repro.common.compile_cache import setup_compile_cache
 from repro.configs.registry import get_config
 from repro.data.synthetic import zipf_tokens
-from repro.launch.mesh import (make_host_mesh, make_production_mesh,
-                               mesh_scope)
+from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.launch.sharding import data_spec, param_shardings
 from repro.launch.steps import make_train_step
 from repro.models.lm import lm_init
@@ -43,6 +43,7 @@ def main(argv=None) -> None:
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--model-parallel", type=int, default=1)
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     mesh = (make_production_mesh(multi_pod=args.multi_pod)
@@ -52,7 +53,7 @@ def main(argv=None) -> None:
                       total_steps=args.steps)
     ckpt = CheckpointManager(os.path.join(args.ckpt_dir, cfg.name), keep=3)
 
-    with mesh_scope(mesh):
+    with jax.set_mesh(mesh):
         params = lm_init(jax.random.PRNGKey(0), cfg)
         ps = param_shardings(params, mesh)
         params = jax.tree.map(jax.device_put, params, ps)

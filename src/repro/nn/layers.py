@@ -8,6 +8,9 @@ Quantization integrates via two hooks threaded through ``apply``:
     or fake-quantizes the layer input (site = '/'-joined param path).
   * weights — a dense array (possibly already fake-quantized), or a
     ``PackedW4`` (serving form), dispatched here.
+
+Dense-weight matmuls and convs run at f32 precision (HIGHEST): on the TPU
+the default takes one bf16 pass over f32 operands.
 """
 from __future__ import annotations
 
@@ -60,7 +63,7 @@ def dense_apply(p: dict, x: jnp.ndarray, *, ctx: QuantContext | None = None,
         if act_qp is not None:
             from repro.kernels import ops
             x = ops.msfp_quantize(x, act_qp)
-        y = x @ w.astype(x.dtype)
+        y = jnp.matmul(x, w.astype(x.dtype), precision=lax.Precision.HIGHEST)
     if "b" in p:
         y = y + p["b"].astype(y.dtype)
     return y
@@ -105,7 +108,8 @@ def conv2d_apply(p: dict, x: jnp.ndarray, *, stride: int = 1,
             x = ops.msfp_quantize(x, act_qp)
         y = lax.conv_general_dilated(
             x, w.astype(x.dtype), window_strides=(stride, stride),
-            padding=padding, dimension_numbers=("NHWC", "HWIO", "NHWC"))
+            padding=padding, dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            precision=lax.Precision.HIGHEST)
     if "b" in p:
         y = y + p["b"].astype(y.dtype)
     return y

@@ -214,15 +214,8 @@ def moe_apply_ep(p: dict, x: jnp.ndarray, cfg: MoEConfig, *,
 
     in_specs = (P(tok_axes, None), P(None, None), P(model_axis, None, None),
                 P(model_axis, None, None), P(model_axis, None, None))
-    try:
-        from jax import shard_map
-        sharded = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+    sharded = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
                             out_specs=P(tok_axes, None), check_vma=False)
-    except (ImportError, TypeError):
-        # older JAX: experimental home and/or the check_rep spelling
-        from jax.experimental.shard_map import shard_map
-        sharded = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                            out_specs=P(tok_axes, None), check_rep=False)
 
     yt = sharded(xt, p["router"]["w"].astype(jnp.float32), p["w_gate"],
                  p["w_up"], p["w_down"])

@@ -142,10 +142,11 @@ def _attn_apply(p, x, cfg, *, ctx, site):
     q = dense_apply(p["q"], h, ctx=ctx, site=f"{site}/q")
     k = dense_apply(p["k"], h, ctx=ctx, site=f"{site}/k")
     v = dense_apply(p["v"], h, ctx=ctx, site=f"{site}/v")
-    w = jax.nn.softmax(jnp.einsum("bqc,bkc->bqk", q, k,
+    f32 = jax.lax.Precision.HIGHEST
+    w = jax.nn.softmax(jnp.einsum("bqc,bkc->bqk", q, k, precision=f32,
                                   preferred_element_type=jnp.float32)
                        * (c ** -0.5), axis=-1).astype(v.dtype)
-    o = jnp.einsum("bqk,bkc->bqc", w, v)
+    o = jnp.einsum("bqk,bkc->bqc", w, v, precision=f32)
     o = dense_apply(p["proj"], o, ctx=ctx, site=f"{site}/proj")
     return x + o.reshape(b, hh, ww, c)
 

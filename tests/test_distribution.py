@@ -60,11 +60,12 @@ def test_train_step_compiles_sharded_8dev():
         from repro.launch.steps import (make_train_step, abstract_params,
                                         abstract_opt, input_specs)
         from repro.optim.adam import AdamConfig
-        from repro.launch.mesh import compat_make_mesh, mesh_scope
-        mesh = compat_make_mesh((4, 2), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         cfg = get_config("qwen1.5-0.5b", smoke=True)
         acfg = AdamConfig()
-        with mesh_scope(mesh):
+        with jax.set_mesh(mesh):
             ap = abstract_params(cfg)
             ao = abstract_opt(ap, acfg)
             ps = param_shardings(ap, mesh)
@@ -76,7 +77,6 @@ def test_train_step_compiles_sharded_8dev():
                          out_shardings=(ps, os_, None)) \\
                 .lower(ap, ao, {"tokens": tokens}).compile()
             ca = co.cost_analysis()
-            ca = ca[0] if isinstance(ca, list) else ca  # old JAX: list of dicts
             print("FLOPS", ca.get("flops", -1) > 0)
             print("OK")
     """)
@@ -95,11 +95,12 @@ def test_decode_step_compiles_sharded_8dev(arch):
                                            data_spec)
         from repro.launch.steps import (abstract_params, input_specs,
                                         make_decode_fn, quantize_abstract)
-        from repro.launch.mesh import compat_make_mesh, mesh_scope
-        mesh = compat_make_mesh((4, 2), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         cfg = get_config("{arch}", smoke=True)
         shape = ShapeSpec("d", 32, 8, "decode")
-        with mesh_scope(mesh):
+        with jax.set_mesh(mesh):
             ap = quantize_abstract(abstract_params(cfg))
             ps = param_shardings(ap, mesh)
             specs = input_specs(cfg, shape)
@@ -111,7 +112,6 @@ def test_decode_step_compiles_sharded_8dev(arch):
                 .lower(ap, specs["caches"], specs["token"],
                        specs["pos"]).compile()
             ca = co.cost_analysis()
-            ca = ca[0] if isinstance(ca, list) else ca
             print("OK", ca.get("flops", 0) > 0)
     """)
     assert "OK True" in out
@@ -124,9 +124,11 @@ def test_checkpoint_restore_onto_different_mesh():
         import tempfile, jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint.ckpt import CheckpointManager
-        from repro.launch.mesh import compat_make_mesh
-        m1 = compat_make_mesh((4, 2), ("data", "model"))
-        m2 = compat_make_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType
+        m1 = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
+        m2 = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         tree = {"w": jnp.arange(64.0).reshape(8, 8)}
         sh1 = {"w": NamedSharding(m1, P("data", "model"))}
         sh2 = {"w": NamedSharding(m2, P("data", "model"))}
@@ -150,14 +152,15 @@ def test_moe_expert_parallel_matches_global():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.nn.moe import MoEConfig, moe_init, moe_apply, moe_apply_ep
-        from repro.launch.mesh import compat_make_mesh, mesh_scope
-        mesh = compat_make_mesh((4, 2), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         cfg = MoEConfig(d_model=32, d_ff=16, n_experts=4, top_k=2,
                         n_shared=1, capacity_factor=8.0)
         key = jax.random.PRNGKey(0)
         p = moe_init(key, cfg, jnp.float32)
         x = jax.random.normal(key, (8, 6, 32))
-        with mesh_scope(mesh):
+        with jax.set_mesh(mesh):
             xg = jax.device_put(x, NamedSharding(mesh, P("data", None, None)))
             y_g = jax.jit(lambda p, x: moe_apply(p, x, cfg))(p, xg)
             y_e = jax.jit(lambda p, x: moe_apply_ep(p, x, cfg))(p, xg)

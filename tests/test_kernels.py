@@ -827,6 +827,102 @@ def test_force_xla_pins_pure_reference(monkeypatch, rng):
         ref.ref_w4a4_conv2d(xc, _pack_conv(wc), qp, dtype=xc.dtype))
 
 
+@pytest.mark.parametrize("op", ["qdq", "w4", "w4a4", "conv"])
+def test_force_pallas_off_tpu_raises(op, rng):
+    """Compiled Pallas needs a TPU: off it, FORCE="pallas" is an error,
+    never a quiet switch to interpret mode."""
+    ops.FORCE = "pallas"
+    assert jax.default_backend() != "tpu"
+    qp = QuantizerParams(KIND_FP_SIGNED, 2, 1, 4, jnp.float32(2.0))
+    w = jnp.asarray(rng.normal(size=(96, 64)).astype(np.float32))
+    x = jnp.asarray(rng.normal(size=(8, 96)).astype(np.float32))
+    wc = jnp.asarray(rng.normal(size=(3, 3, 4, 8)).astype(np.float32))
+    xc = jnp.asarray(rng.normal(size=(1, 6, 6, 4)).astype(np.float32))
+    call = {"qdq": lambda: ops.msfp_quantize(x, qp),
+            "w4": lambda: ops.w4_matmul(x, pack_weight(w, qp)),
+            "w4a4": lambda: ops.w4a4_matmul(x, pack_weight(w, qp), qp),
+            "conv": lambda: ops.w4a4_conv2d(xc, _pack_conv(wc), qp)}[op]
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        call()
+
+
+@pytest.mark.parametrize("stride,cin,want", [((1, 1), 128, "implicit"),
+                                             ((2, 2), 128, "im2col"),
+                                             ((1, 1), 4096, "im2col")],
+                         ids=["unit_stride", "strided", "over_budget"])
+def test_conv_route_auto_compiled(stride, cin, want):
+    """Compiled auto routing sends to im2col what the implicit kernel
+    cannot compile: strided taps, and slabs over the VMEM budget."""
+    from repro.core.qmodule import PackedW4
+    from repro.kernels.conv import implicit_supported
+
+    x = jax.ShapeDtypeStruct((4, 32, 32, cin), jnp.float32)
+    pw = PackedW4(None, None, None, 2, 1, True, (3, 3, cin, 128))
+    assert ops._conv_route(x, pw, stride, ((1, 1), (1, 1)), fused=True,
+                           interpret=False) == want
+    assert implicit_supported(x.shape, pw.shape, stride, ((1, 1), (1, 1)),
+                              fused=True) == (want == "implicit")
+
+
+@pytest.mark.parametrize("n_half,interpret,want", [
+    (64, True, 64), (64, False, 128), (128, False, 128), (384, False, 128),
+    (320, True, 128), (32, False, 128)])
+def test_lane_tile(n_half, interpret, want):
+    """Compiled column tiles are whole 128-lane tiles (Mosaic's block
+    rule); interpret mode keeps the half's own narrower width."""
+    from repro.kernels.w4_matmul import lane_tile
+
+    assert lane_tile(n_half, interpret=interpret) == want
+
+
+def _dot_precisions(jaxpr) -> list:
+    """(lhs dtype, precision) of every dot_general, nested jaxprs too."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append((eqn.invars[0].aval.dtype, eqn.params["precision"]))
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, Jaxpr):
+                    found += _dot_precisions(sub)
+    return found
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+@pytest.mark.parametrize("kernel", ["w4a4_matmul", "implicit_conv"])
+def test_compiled_kernel_dots_keep_f32(kernel, dtype):
+    """On the TPU a dot of f32 operands at the default precision is one
+    bf16 MXU pass. The compiled kernels' f32 dots ask for HIGHEST; bf16
+    dots keep the default, the one pass Mosaic accepts for them."""
+    from repro.core.qmodule import PackedW4
+    from repro.kernels.conv import w4a4_conv2d_implicit
+    from repro.kernels.w4_matmul import w4a4_matmul_2d
+
+    act = QuantizerParams(KIND_FP_SIGNED, 2, 1, 4, jnp.float32(6.0))
+    if kernel == "w4a4_matmul":
+        fn = lambda x, p, s: w4a4_matmul_2d(  # noqa: E731
+            x, p, s, 0.0, 6.0, 0.0, exp_bits=2, man_bits=1, signed=True,
+            act_exp_bits=2, act_man_bits=1, act_signed=True)
+        args = ((8, 64), (64, 64), (128,))
+    else:
+        fn = lambda x, p, s: w4a4_conv2d_implicit(  # noqa: E731
+            x, PackedW4(p, s, jnp.float32(0.0), 2, 1, True, (3, 3, 8, 128)),
+            act, stride=(1, 1), padding="SAME")
+        args = ((1, 4, 4, 8), (72, 64), (128,))
+    jaxpr = jax.make_jaxpr(fn)(jnp.ones(args[0], dtype),
+                               jnp.ones(args[1], jnp.uint8),
+                               jnp.ones(args[2], jnp.float32))
+    dots = _dot_precisions(jaxpr.jaxpr)
+    assert dots
+    want = (jax.lax.Precision.HIGHEST,) * 2 if dtype == jnp.float32 else None
+    for lhs, precision in dots:
+        assert lhs == dtype and precision == want, (lhs, precision)
+
+
 def test_default_cpu_dispatch_routes_to_fast_path(monkeypatch, rng):
     """Unforced off-TPU dispatch serves via xla_serve (matmul, fused,
     conv, qdq) — the reference oracles are for tests, not serving."""

@@ -27,6 +27,33 @@ def test_cells_cover_40_minus_long_skips():
     assert ("qwen1.5-0.5b", "long_500k") not in cs
 
 
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/jax-cache"],
+                         ids=["default", "from_env"])
+def test_compile_cache_placement(monkeypatch, env_dir):
+    """The persistent compile cache goes where JAX_COMPILATION_CACHE_DIR
+    says, set by nothing in code; else to a fixed, gitignored directory
+    inside the checkout."""
+    from pathlib import Path
+
+    from repro.common import compile_cache
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: updates.append(a))
+    if env_dir is None:
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    else:
+        monkeypatch.setenv(compile_cache.ENV_VAR, env_dir)
+    got = compile_cache.setup_compile_cache()
+    root = Path(__file__).resolve().parents[1]
+    if env_dir is None:
+        assert got == str(root / ".jax_cache")
+        assert updates == [("jax_compilation_cache_dir", got)]
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split("\n")
+    else:
+        assert got == env_dir and updates == []
+
+
 def test_input_specs_shapes():
     cfg = get_config("llava-next-mistral-7b")
     sp = input_specs(cfg, SHAPES["prefill_32k"])

@@ -232,13 +232,16 @@ def test_weight_bank_merges_and_packs_per_segment():
     dq = np.asarray(dequant_weight(flat0["l0/w"], jnp.float32))
     scale = float(plan.sites["l0/w"].qp.maxval)
     assert np.abs(w.clip(-scale, scale) - dq).max() <= scale / 4  # E2M1 step
-    # a segment with different routing packs different bytes
+    # a site routed to a different slot packs different bytes; a site
+    # routed to the same slot packs the same bytes
     other = next((s for s in bank.segments if s.slots != bank.segments[0].slots),
                  None)
     assert other is not None, "toy router collapsed to one signature"
     po = flatten_paths(bank.params_for_segment(other.index))
-    assert not np.array_equal(np.asarray(flat0["l0/w"].packed),
-                              np.asarray(po["l0/w"].packed))
+    for i, name in enumerate(names):
+        same_slot = other.slots[i] == bank.segments[0].slots[i]
+        assert np.array_equal(np.asarray(flat0[name].packed),
+                              np.asarray(po[name].packed)) == same_slot, name
 
 
 def test_weight_bank_lru_and_stats():
