@@ -41,11 +41,20 @@ feeds the scheduler's ``CostModel`` with observed forward and
 segment-build durations measured on the engine clock.
 
 Passing an enabled ``serving.obs.Observability`` turns on structured
-telemetry: request/tick/fetch/forward spans on the engine clock, per-tick
-registry samples, and propagation of the obs bundle into the scheduler
-and weight bank (their decision/build spans land in the same trace).
-With the default ``NULL_OBS`` every instrumentation point is one
-``obs.enabled`` branch — the serving path is unchanged.
+telemetry: request lifecycle events and the tick's phase spans on the
+engine clock (``tick`` > ``admit``, ``schedule``, ``bank_fetch``,
+``forward`` > per partition ``batch``, ``dispatch``, ``unbatch``, then
+``advance`` and ``prefetch``; see ``serving.obs``), per-tick counter
+tracks, JAX's compiles as ``compile`` spans on a wall clock, and
+propagation of the obs bundle into the scheduler and weight bank (their
+decision/build spans land in the same trace). With the default
+``NULL_OBS`` every instrumentation point is one ``obs.enabled`` branch —
+the serving path is unchanged.
+
+Two scheduler counters are always on: ``inflight_request_ticks`` sums
+the requests in flight at each selection, ``served_request_ticks`` those
+the selection served; their difference is request-ticks spent waiting in
+flight for another segment's or a later bucket's turn.
 """
 from __future__ import annotations
 
@@ -60,7 +69,7 @@ from repro.diffusion.samplers import (sampler_advance, sampler_init,
 from repro.diffusion.schedule import NoiseSchedule
 from repro.nn.unet import UNetConfig, unet_apply
 from repro.quant.calibrate import QuantContext
-from repro.serving.obs import NULL_OBS, Observability
+from repro.serving.obs import NULL_OBS, NULL_SPAN, Observability
 from repro.serving.scheduler import (ContinuousBatcher, GenRequest,
                                      RequestState, bucket_of)
 from repro.serving.traffic.metrics import percentile
@@ -130,6 +139,9 @@ class DiffusionServingEngine:
             t0 = time.monotonic()
             self._now = now_fn or (lambda: time.monotonic() - t0)
             self._advance = None
+        # on its own monotonic clock (neither virtual nor simulated): the
+        # obs bundle may then time JAX's compiles on it
+        self.wall_clock = clock is None and now_fn is None
         self.max_idle_sleep = max_idle_sleep
         self.prefetch_enabled = prefetch
         # background builds only make sense when real time passes during
@@ -147,8 +159,11 @@ class DiffusionServingEngine:
                 self.bank.obs = self.obs
             self._h_forward = self.obs.metrics.histogram(
                 "engine_forward_seconds",
-                help="engine-clock batched-forward durations (the same "
-                     "observations the scheduler cost EWMA consumes)")
+                help="engine-clock host time of the tick's batched "
+                     "forwards: batching, dispatch and unbatching, not "
+                     "the device's forward time, which async dispatch "
+                     "leaves out (the same observations the scheduler "
+                     "cost EWMA consumes)")
             self._h_fetch = self.obs.metrics.histogram(
                 "bank_fetch_seconds",
                 help="engine-clock stalls fetching the tick's segment")
@@ -162,6 +177,8 @@ class DiffusionServingEngine:
         self.n_idle_sleeps = 0
         self.n_finished = 0
         self.n_expired = 0
+        self.inflight_request_ticks = 0
+        self.served_request_ticks = 0
         self._latencies: list[float] = []    # scalars only; never evicted
         self.results: dict[int, RequestState] = {}
         # traffic-subsystem hooks; each receives the RequestState (or the
@@ -213,131 +230,140 @@ class DiffusionServingEngine:
 
     def tick(self) -> list[RequestState]:
         obs = self.obs
-        tick_span = None
         if obs.enabled:
             obs.tracer.set_track(self.replica or self.model)
-            tick_span = obs.tracer.begin(
-                "tick", cat="engine", args={"tick": self.tick_count})
-        now = self._now()
-        admitted, expired = self.batcher.admit(now, self.tick_count)
-        if obs.enabled:
-            for rs in admitted:
-                obs.tracer.async_instant("admit", rs.req.rid, cat="request")
-        for rs in expired:
-            rs.finished_at = now
-            self.results[rs.req.rid] = rs
-            self.n_expired += 1
-            if obs.enabled:
-                obs.tracer.async_end("request", rs.req.rid, cat="request",
-                                     args={"outcome": "expired"})
-            for cb in self.on_expire:
-                cb(rs)
+            with obs.tracer.span("tick", cat="engine",
+                                 args={"tick": self.tick_count}) as tick_sp:
+                finished = self._tick(tick_sp)
+            obs.sample(self)
+        else:
+            finished = self._tick(NULL_SPAN)
+        for cb in self.on_tick_end:
+            cb(self)
+        return finished
+
+    def _tick(self, tick_sp) -> list[RequestState]:
+        """The tick's phases, each a child span of ``tick_sp`` when obs is
+        on; a raise closes every open span on its way out."""
+        obs = self.obs
+        on = obs.enabled
+        tr = obs.tracer
+        with tr.span("admit") if on else NULL_SPAN:
+            now = self._now()
+            admitted, expired = self.batcher.admit(now, self.tick_count)
+            if on:
+                for rs in admitted:
+                    tr.async_instant("admit", rs.req.rid, cat="request")
+            for rs in expired:
+                rs.finished_at = now
+                self.results[rs.req.rid] = rs
+                self.n_expired += 1
+                if on:
+                    tr.async_end("request", rs.req.rid, cat="request",
+                                 args={"outcome": "expired"})
+                for cb in self.on_expire:
+                    cb(rs)
         if not self.batcher.inflight:
-            if obs.enabled:
-                tick_span.args["idle"] = True
-                obs.tracer.end(tick_span)
-                obs.sample(self)
-            for cb in self.on_tick_end:
-                cb(self)
+            tick_sp.set("idle", True)
             return []
-        groups = self.batcher.groups(
-            lambda rs: self.bank.segment_of(sampler_needed_t(rs.state)))
-        seg, members = self.batcher.select(groups, self.tick_count, now=now)
-        self.batcher.current_seg = seg
-        fetch_span = None
-        if obs.enabled:
-            tick_span.args.update(
+
+        with tr.span("schedule") if on else NULL_SPAN:
+            groups = self.batcher.groups(
+                lambda rs: self.bank.segment_of(sampler_needed_t(rs.state)))
+            seg, members = self.batcher.select(groups, self.tick_count,
+                                               now=now)
+            self.batcher.current_seg = seg
+            self.inflight_request_ticks += len(self.batcher.inflight)
+            self.served_request_ticks += len(members)
+            # eval items: (rs, role, t, x (1,H,W,C), y)
+            items = []
+            for rs in members:
+                t = sampler_needed_t(rs.state)
+                x = rs.state.eval_x
+                if rs.req.guidance_scale > 0:
+                    items.append((rs, _UNCOND, t, x, None))
+                    items.append((rs, _COND, t, x, rs.req.y))
+                else:
+                    items.append((rs, _PLAIN, t, x, rs.req.y))
+        if on:
+            tick_sp.span.args.update(
                 {"seg": seg, "members": [rs.req.rid for rs in members],
                  "n_groups": len(groups), "policy": self.batcher.policy})
-            fetch_span = obs.tracer.begin("bank_fetch", cat="bank",
-                                          args={"seg": seg})
-        t_fetch = self._now()
-        misses_before = self.bank.misses
-        joins_before = self.bank.build_joins
-        params = self.bank.params_for_segment(seg)
-        if self.bank.misses > misses_before:
-            # cold fetch: the observed stall is the segment-switch cost
-            self.batcher.cost.observe_switch(self._now() - t_fetch)
-        elif self.bank.build_joins > joins_before:
-            # joined an async build mid-way: with prefetch on this is the
-            # common cold path (prefetch registers the build before the
-            # fetch, so `misses` never moves) — without it the switch
-            # EWMA would stay pinned to the first cold build forever.
-            # The stall is the remaining ~half of a build on average.
-            self.batcher.cost.observe_switch(2 * (self._now() - t_fetch))
-        if obs.enabled:
-            fetch_span.args["outcome"] = (
-                "miss" if self.bank.misses > misses_before
-                else "join" if self.bank.build_joins > joins_before
-                else "hit")
-            obs.tracer.end(fetch_span)
-            self._h_fetch.observe(self._now() - t_fetch)
 
-        # build eval items: (rs, role, t, x (1,H,W,C), y)
-        items = []
-        for rs in members:
-            t = sampler_needed_t(rs.state)
-            x = rs.state.eval_x
-            if rs.req.guidance_scale > 0:
-                items.append((rs, _UNCOND, t, x, None))
-                items.append((rs, _COND, t, x, rs.req.y))
-            else:
-                items.append((rs, _PLAIN, t, x, rs.req.y))
+        with (tr.span("bank_fetch", cat="bank", args={"seg": seg})
+              if on else NULL_SPAN) as fetch_sp:
+            t_fetch = self._now()
+            misses_before = self.bank.misses
+            joins_before = self.bank.build_joins
+            params = self.bank.params_for_segment(seg)
+            if self.bank.misses > misses_before:
+                # cold fetch: the observed stall is the segment-switch cost
+                self.batcher.cost.observe_switch(self._now() - t_fetch)
+            elif self.bank.build_joins > joins_before:
+                # joined an async build mid-way: with prefetch on this is
+                # the common cold path (prefetch registers the build
+                # before the fetch, so `misses` never moves) — without it
+                # the switch EWMA would stay pinned to the first cold
+                # build forever. The stall is the remaining ~half of a
+                # build on average.
+                self.batcher.cost.observe_switch(2 * (self._now() - t_fetch))
+            if on:
+                fetch_sp.set("outcome", (
+                    "miss" if self.bank.misses > misses_before
+                    else "join" if self.bank.build_joins > joins_before
+                    else "hit"))
+                self._h_fetch.observe(self._now() - t_fetch)
 
-        fwd_span = None
-        if obs.enabled:
-            fwd_span = obs.tracer.begin("forward", cat="engine",
-                                        args={"items": len(items)})
-        t_compute = self._now()
-        n_jit_before = len(self._jit)
-        eps_by_item = self._run_partitions(params, items)
-        compiled = len(self._jit) > n_jit_before
-        if not compiled:
-            # skip ticks that traced+compiled a new (bucket, has_y)
-            # forward: seeding the EWMA with compile time would poison
-            # slack estimates for many subsequent ticks
-            self.batcher.cost.observe_eval(self._now() - t_compute,
-                                           self._last_padded_rows)
-        if obs.enabled:
+        with (tr.span("forward", cat="engine", args={"items": len(items)})
+              if on else NULL_SPAN) as fwd_sp:
+            t_compute = self._now()
+            n_jit_before = len(self._jit)
+            eps_by_item = self._run_partitions(params, items)
+            compiled = len(self._jit) > n_jit_before
             dt = self._now() - t_compute
-            fwd_span.args.update({"padded_rows": self._last_padded_rows,
-                                  "compiled": compiled})
-            obs.tracer.end(fwd_span)
-            # the same engine-clock observation the cost EWMA consumed
             if not compiled:
-                self._h_forward.observe(dt)
+                # skip ticks that traced+compiled a new (bucket, has_y)
+                # forward: seeding the EWMA with compile time would poison
+                # slack estimates for many subsequent ticks
+                self.batcher.cost.observe_eval(dt, self._last_padded_rows)
+            if on:
+                fwd_sp.set("padded_rows", self._last_padded_rows)
+                fwd_sp.set("compiled", compiled)
+                # the same engine-clock observation the cost EWMA consumed
+                if not compiled:
+                    self._h_forward.observe(dt)
 
-        finished = []
-        tick = self.tick_count
-        for rs in members:
-            parts = eps_by_item[id(rs)]
-            if _PLAIN in parts:
-                eps = parts[_PLAIN]
-            else:
-                s = rs.req.guidance_scale
-                eps = parts[_UNCOND] + s * (parts[_COND] - parts[_UNCOND])
-            sampler_advance(rs.state, eps)
-            rs.last_advance_tick = tick
-            rs.n_evals += 1
-            if obs.enabled:
-                obs.tracer.async_instant("eval", rs.req.rid, cat="request",
-                                         args={"n_evals": rs.n_evals})
-            if rs.state.done:
-                rs.x0 = rs.state.x
-                rs.finished_at = self._now()
-                self.batcher.retire(rs)
-                self.results[rs.req.rid] = rs
-                self.n_finished += 1
-                self._latencies.append(rs.latency)
-                finished.append(rs)
-                if obs.enabled:
-                    obs.tracer.async_end(
-                        "request", rs.req.rid, cat="request",
-                        args={"outcome": "complete",
-                              "n_evals": rs.n_evals,
-                              "latency_s": rs.latency})
-                for cb in self.on_complete:
-                    cb(rs)
+        with tr.span("advance") if on else NULL_SPAN:
+            finished = []
+            tick = self.tick_count
+            for rs in members:
+                parts = eps_by_item[id(rs)]
+                if _PLAIN in parts:
+                    eps = parts[_PLAIN]
+                else:
+                    s = rs.req.guidance_scale
+                    eps = parts[_UNCOND] + s * (parts[_COND] - parts[_UNCOND])
+                sampler_advance(rs.state, eps)
+                rs.last_advance_tick = tick
+                rs.n_evals += 1
+                if on:
+                    tr.async_instant("eval", rs.req.rid, cat="request",
+                                     args={"n_evals": rs.n_evals})
+                if rs.state.done:
+                    rs.x0 = rs.state.x
+                    rs.finished_at = self._now()
+                    self.batcher.retire(rs)
+                    self.results[rs.req.rid] = rs
+                    self.n_finished += 1
+                    self._latencies.append(rs.latency)
+                    finished.append(rs)
+                    if on:
+                        tr.async_end("request", rs.req.rid, cat="request",
+                                     args={"outcome": "complete",
+                                           "n_evals": rs.n_evals,
+                                           "latency_s": rs.latency})
+                    for cb in self.on_complete:
+                        cb(rs)
         self.tick_count += 1
         if self.prefetch_enabled:
             # Requests that just advanced may cross into a new routing
@@ -346,15 +372,11 @@ class DiffusionServingEngine:
             # so the next segment merges/packs while this segment's
             # forwards keep running; a later fetch joins the in-progress
             # build instead of rebuilding.
-            for s in {self.bank.segment_of(sampler_needed_t(rs.state))
-                      for rs in members if not rs.state.done}:
-                self.bank.prefetch(s, block=not self.async_prefetch)
-        if obs.enabled:
-            tick_span.args["finished"] = len(finished)
-            obs.tracer.end(tick_span)
-            obs.sample(self)
-        for cb in self.on_tick_end:
-            cb(self)
+            with tr.span("prefetch") if on else NULL_SPAN:
+                for s in {self.bank.segment_of(sampler_needed_t(rs.state))
+                          for rs in members if not rs.state.done}:
+                    self.bank.prefetch(s, block=not self.async_prefetch)
+        tick_sp.set("finished", len(finished))
         return finished
 
     def _run_partitions(self, params, items) -> dict[int, dict]:
@@ -364,22 +386,26 @@ class DiffusionServingEngine:
         and without a label cannot share a forward; each partition still
         batches arbitrary timesteps (``t`` is per-sample).
         """
+        on = self.obs.enabled
+        tr = self.obs.tracer
         eps_by_item: dict[int, dict] = {}
         padded_rows = 0
         for has_y in (False, True):
-            part = [it for it in items if (it[4] is not None) == has_y]
-            if not part:
+            if not any((it[4] is not None) == has_y for it in items):
                 continue
-            x = jnp.concatenate([it[3] for it in part], axis=0)
-            tb = jnp.asarray([it[2] for it in part], jnp.float32)
-            y = (jnp.asarray([it[4] for it in part], jnp.int32)
-                 if has_y else None)
+            with tr.span("batch") if on else NULL_SPAN:
+                part = [it for it in items if (it[4] is not None) == has_y]
+                x = jnp.concatenate([it[3] for it in part], axis=0)
+                tb = jnp.asarray([it[2] for it in part], jnp.float32)
+                y = (jnp.asarray([it[4] for it in part], jnp.int32)
+                     if has_y else None)
             eps = self._forward(params, x, tb, y)
-            self.n_forwards += 1
-            self.n_samples_batched += len(part)
-            padded_rows += self._bucket(len(part))
-            for j, (rs, role, *_rest) in enumerate(part):
-                eps_by_item.setdefault(id(rs), {})[role] = eps[j:j + 1]
+            with tr.span("unbatch") if on else NULL_SPAN:
+                self.n_forwards += 1
+                self.n_samples_batched += len(part)
+                padded_rows += self._bucket(len(part))
+                for j, (rs, role, *_rest) in enumerate(part):
+                    eps_by_item.setdefault(id(rs), {})[role] = eps[j:j + 1]
         self._last_padded_rows = padded_rows
         for cb in self.on_forward:
             cb(self, padded_rows)
@@ -392,28 +418,38 @@ class DiffusionServingEngine:
     _bucket = staticmethod(bucket_of)
 
     def _forward(self, params, x, tb, y):
+        on = self.obs.enabled
+        tr = self.obs.tracer
         n = x.shape[0]
         b = self._bucket(n)
         if b != n:
-            # Pad with copies of row 0 (always finite through norms) and
-            # mask by slicing the padded outputs away below.
-            pad = b - n
-            x = jnp.concatenate([x, jnp.repeat(x[:1], pad, axis=0)], axis=0)
-            tb = jnp.concatenate([tb, jnp.repeat(tb[:1], pad)], axis=0)
-            if y is not None:
-                y = jnp.concatenate([y, jnp.repeat(y[:1], pad)], axis=0)
-            self.n_padded_samples += pad
+            with tr.span("batch") if on else NULL_SPAN:
+                # Pad with copies of row 0 (always finite through norms)
+                # and mask by slicing the padded outputs away below.
+                pad = b - n
+                x = jnp.concatenate([x, jnp.repeat(x[:1], pad, axis=0)],
+                                    axis=0)
+                tb = jnp.concatenate([tb, jnp.repeat(tb[:1], pad)], axis=0)
+                if y is not None:
+                    y = jnp.concatenate([y, jnp.repeat(y[:1], pad)], axis=0)
+                self.n_padded_samples += pad
         key = (b, y is not None)
-        if key not in self._jit:
-            if y is None:
-                self._jit[key] = jax.jit(
-                    lambda p, x, tb: self._apply(p, x, tb, None, self.ctx))
-            else:
-                self._jit[key] = jax.jit(
-                    lambda p, x, tb, y: self._apply(p, x, tb, y, self.ctx))
-        fn = self._jit[key]
-        eps = fn(params, x, tb) if y is None else fn(params, x, tb, y)
-        return eps[:n]
+        compiled = key not in self._jit
+        with (tr.span("dispatch", args={"compiled": compiled})
+              if on else NULL_SPAN):
+            if compiled:
+                if y is None:
+                    self._jit[key] = jax.jit(
+                        lambda p, x, tb: self._apply(p, x, tb, None,
+                                                     self.ctx))
+                else:
+                    self._jit[key] = jax.jit(
+                        lambda p, x, tb, y: self._apply(p, x, tb, y,
+                                                        self.ctx))
+            fn = self._jit[key]
+            eps = fn(params, x, tb) if y is None else fn(params, x, tb, y)
+        with tr.span("unbatch") if on else NULL_SPAN:
+            return eps[:n]
 
     def pop_result(self, rid: int) -> RequestState:
         """Hand a finished request to its caller and release the engine's
@@ -476,6 +512,8 @@ class DiffusionServingEngine:
              "forwards": self.n_forwards,
              "mean_batch": (self.n_samples_batched / self.n_forwards
                             if self.n_forwards else 0.0),
+             "inflight_request_ticks": self.inflight_request_ticks,
+             "served_request_ticks": self.served_request_ticks,
              "compiled_forwards": len(self._jit),
              "buckets": buckets,
              "padded_samples": self.n_padded_samples,

@@ -122,6 +122,7 @@ class LMServingEngine:
             t0 = time.monotonic()
             self._now = now_fn or (lambda: time.monotonic() - t0)
             self._advance = None
+        self.wall_clock = clock is None and now_fn is None
         self.max_idle_sleep = max_idle_sleep
         # one segment, fetched on the first tick: nothing to prefetch,
         # but SimClock.attach writes this flag on any engine it drives

@@ -205,7 +205,8 @@ def test_report_mentions_counts_and_violation():
 
 
 def _fake_engine(bank):
-    """The attribute surface obs.sample() reads, wired to a real bank."""
+    """The attribute surface obs.sample() and obs.set_gauges() read,
+    wired to a real bank."""
     batcher = SimpleNamespace(pending=[], inflight=[], preemptions=0,
                               deadline_saves=0,
                               cost=SimpleNamespace(sample_s=0.0,
@@ -242,6 +243,7 @@ def test_bank_obs_lock_population_under_concurrent_load():
         try:
             while not stop.is_set():
                 obs.sample(eng)
+                obs.set_gauges(eng)
                 with obs.tracer.span("tick", cat="engine") as sp:
                     sp.set("pending", 0)
         except Exception as e:   # pragma: no cover
@@ -282,9 +284,9 @@ def test_bank_obs_lock_population_under_concurrent_load():
     build_spans = [e for e in obs.tracer.events()
                    if e["name"] == "bank_build"]
     assert len(build_spans) == bank.builds == len(segs)
-    # registry gauges sampled concurrently converged to the bank's final
+    # registry gauges set concurrently converged to the bank's final
     # counters once the churn drained
-    obs.sample(eng)
+    obs.set_gauges(eng)
     snap = obs.metrics.snapshot()
     assert snap["bank_builds"] == bank.builds
     assert snap["bank_misses"] == bank.misses

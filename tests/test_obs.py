@@ -15,10 +15,15 @@ What this suite pins down, in order:
   * kernel-route profiling: per-route counts reconcile with the ops
     dispatch rules the route-forcing tests in test_kernels pin,
   * MetricsCollector retention: capped buffers compact instead of drop —
-    summary totals stay exact, and the scheduler/bank counters ride in.
+    summary totals stay exact, and the scheduler/bank counters ride in,
+  * the tick's phase spans: nesting, and children that cover each busy
+    tick and each forward; the scheduler's served / in-flight counters,
+  * JAX's compiles as ``compile`` spans (wall clock only), and the
+    annotation bridge that puts every span on the profiler's host plane.
 """
 import json
 import threading
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -35,8 +40,10 @@ from repro.configs.diffusion_presets import tiny_ddim
 from repro.core.qmodule import pack_weight
 from repro.kernels import ops
 from repro.quant.fakequant import KIND_FP_SIGNED, QuantizerParams
-from repro.serving import DiffusionServingEngine, VirtualClock
+from repro.serving import DiffusionServingEngine, VirtualClock, WeightBank
+from repro.serving import engine as engine_mod
 from repro.serving.obs import NULL_OBS, Observability, SpanTracer
+from repro.serving.obs import tracer as tracer_mod
 from repro.serving.obs.metrics import MetricsRegistry
 from repro.serving.traffic import load_trace, submit_trace
 from repro.serving.traffic.metrics import MetricsCollector, _Event
@@ -419,3 +426,253 @@ def test_summary_folds_scheduler_and_bank_counters():
     assert s["prefetch_hits"] == eng.bank.prefetch_hits
     assert s["preemptions"] == eng.batcher.preemptions
     assert s["requests"] == 3
+
+
+# ---------------------------------------------------------------------------
+# The tick's phases, the scheduler's counters, compiles, the device trace.
+# ---------------------------------------------------------------------------
+
+_PHASES = ("admit", "schedule", "bank_fetch", "forward", "advance",
+           "prefetch")
+
+
+def _inside(e, parent):
+    return (e["tid"] == parent["tid"] and parent["ts"] <= e["ts"]
+            and e["ts"] + e["dur"] <= parent["ts"] + parent["dur"])
+
+
+def test_tick_phase_spans_nest_and_cover_each_tick(monkeypatch):
+    """Time passes only inside the work each phase names (a manual clock
+    stepped by the calls each phase makes), so a busy tick's direct
+    children must add up to the tick exactly, and ``batch`` +
+    ``dispatch`` + ``unbatch`` to each ``forward``."""
+    clock = [0.0]
+
+    def stepping(fn):
+        def call(*a, **kw):
+            clock[0] += 1.0
+            return fn(*a, **kw)
+        return call
+
+    class _SteppingJnp:            # every jnp call the engine makes
+        def __getattr__(self, name):
+            clock[0] += 1.0
+            return getattr(jnp, name)
+
+    monkeypatch.setattr(engine_mod, "jnp", _SteppingJnp())
+    monkeypatch.setattr(engine_mod, "sampler_advance",
+                        stepping(engine_mod.sampler_advance))
+    monkeypatch.setattr(engine_mod, "sampler_needed_t",
+                        stepping(engine_mod.sampler_needed_t))
+    bank = _multi_segment_bank()
+    bank.params_for_segment = stepping(bank.params_for_segment)
+    bank.prefetch = stepping(bank.prefetch)
+    obs = Observability()
+    eng = DiffusionServingEngine(
+        tiny_ddim(4), SCHED, bank, max_batch=3, obs=obs,
+        apply_fn=stepping(lambda params, x, tb, y, ctx: 0.1 * x),
+        now_fn=lambda: clock[0], async_prefetch=False)
+    eng.batcher.admit = stepping(eng.batcher.admit)
+    for i in range(5):
+        eng.submit(steps=3 + i % 3, seed=i)
+    with jax.disable_jit():        # the forward's Python runs every call
+        eng.run()
+
+    # spans publish as they end: a tick's descendants precede it, and a
+    # forward's children lie between the tick's bank_fetch and forward
+    evs = [e for e in obs.tracer.events()
+           if e["ph"] == "X" and e["cat"] in ("engine", "bank")
+           and e["name"] != "bank_build"]
+    held, busy = [], 0
+    for e in evs:
+        if e["name"] != "tick":
+            held.append(e)
+            continue
+        phases = [h for h in held if h["name"] in _PHASES]
+        names = tuple(h["name"] for h in phases)
+        if e["args"].get("idle"):
+            assert names == ("admit",)
+        else:
+            busy += 1
+            assert names == _PHASES
+            # prefetch has no work once every member finished
+            assert all(h["dur"] > 0 for h in phases[:-1])
+            fwd = phases[3]
+            k = held.index(fwd)
+            kids = held[held.index(phases[2]) + 1:k]
+            assert {h["name"] for h in kids} <= {"batch", "dispatch",
+                                                 "unbatch"}
+            dispatch = [h for h in kids if h["name"] == "dispatch"]
+            assert len(dispatch) == 1 and "compiled" in dispatch[0]["args"]
+            assert sum(h["dur"] for h in kids) == fwd["dur"] > 0
+            # padding to the bucket is batching too: a second batch span
+            assert sum(h["name"] == "batch" for h in kids) == (
+                1 + (fwd["args"]["padded_rows"] != fwd["args"]["items"]))
+            for h in kids:
+                assert _inside(h, fwd)
+        for a, b in zip(phases, phases[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"]
+        for h in held:
+            assert _inside(h, e)
+        assert sum(h["dur"] for h in phases) == e["dur"]
+        held = []
+    assert busy == eng.n_forwards > 0 and held == []
+
+
+def test_phase_spans_close_when_the_tick_raises():
+    obs = Observability()
+
+    def broken(params, x, tb, y, ctx):
+        raise RuntimeError("forward failed")
+
+    eng = DiffusionServingEngine(tiny_ddim(4), SCHED,
+                                 _single_segment_bank(), apply_fn=broken,
+                                 obs=obs, clock=VirtualClock())
+    eng.submit(steps=3)
+    with pytest.raises(RuntimeError, match="forward failed"):
+        eng.tick()
+    names = [e["name"] for e in obs.tracer.events() if e["ph"] == "X"]
+    assert names[-3:] == ["dispatch", "forward", "tick"]
+    assert obs.tracer._stacks[0] == []
+
+
+def _two_segment_bank():
+    """Two routing segments, the halves of [0, T)."""
+    params = {"l0": {"w": jnp.ones((4, 4))}}
+    from repro.common.tree import flatten_paths
+    from repro.serving import default_serving_plan
+    sig = np.repeat([[0], [1]], T // 2, axis=0)
+    return WeightBank(params, default_serving_plan(flatten_paths(params)),
+                      {}, None, None, T, signatures=sig)
+
+
+@pytest.mark.parametrize("segments", [1, 2])
+def test_served_request_ticks_count_the_selection(segments):
+    bank = _single_segment_bank() if segments == 1 else _two_segment_bank()
+    assert bank.n_segments == segments
+    eng = _engine(bank=bank, max_batch=4, clock=VirtualClock())
+    seen = []
+    select = eng.batcher.select
+
+    def spy(groups, tick, now=None):
+        seg, members = select(groups, tick, now=now)
+        seen.append((len(groups), len(eng.batcher.inflight), len(members)))
+        return seg, members
+
+    eng.batcher.select = spy
+    for i, steps in enumerate((2, 8, 8)):
+        eng.submit(steps=steps, seed=i)
+    eng.run()
+    st = eng.stats()
+    assert st["inflight_request_ticks"] == eng.inflight_request_ticks == sum(
+        n for _, n, _ in seen)
+    assert st["served_request_ticks"] == eng.served_request_ticks == sum(
+        m for _, _, m in seen)
+    if segments == 1:
+        assert st["served_request_ticks"] == st["inflight_request_ticks"]
+    else:
+        # the 2-step request crosses into the low half while the others
+        # are still in the high one: the segments split them
+        assert any(g > 1 for g, _, _ in seen)
+        assert st["served_request_ticks"] < st["inflight_request_ticks"]
+
+
+def _probe_fn(v):
+    return v * 3.0 + 1.0
+
+
+def test_compile_spans_once_per_new_shape_inside_the_open_span():
+    obs = Observability()
+    _engine(obs=obs)                  # wall clock: the listener registers
+    tr = obs.tracer
+    f = jax.jit(_probe_fn)            # a fresh cache: every shape is new
+    a3, a5 = np.ones(3, np.float32), np.ones(5, np.float32)
+    try:
+        with tr.span("outer"):
+            f(a3)
+            with tr.span("inner"):
+                f(a5)
+                f(a3)
+            f(a5)
+        obs.close()
+        f(np.ones(7, np.float32))     # after close: not recorded
+    finally:
+        obs.close()
+    evs = tr.events()
+    assert any(e["name"] == "compile_listener" and e["cat"] == "jit"
+               for e in evs)
+    outer = next(e for e in evs if e["name"] == "outer")
+    inner = next(e for e in evs if e["name"] == "inner")
+    mine = [e for e in evs if e["name"] == "compile"
+            and "_probe_fn" in e["args"]["fun_name"]]
+    assert {e["cat"] for e in mine} == {"jit"}
+    built = [e for e in mine if e["args"]["stage"] == "backend_compile"]
+    assert len(built) == 2            # shapes 3 and 5, once each
+    first, second = sorted(built, key=lambda e: e["ts"])
+    assert _inside(first, outer) and not _inside(first, inner)
+    assert _inside(second, inner)
+    for e in mine:
+        assert _inside(e, outer)
+    counts = {k: v for k, v in obs.metrics.snapshot().items()
+              if k.startswith("jit_compiles_total") and "_probe_fn" in k}
+    assert sum(v for k, v in counts.items()
+               if 'stage="backend_compile"' in k) == 2
+
+
+def test_null_obs_and_virtual_clock_register_nothing(monkeypatch):
+    registered = []
+    monkeypatch.setattr(jax.monitoring,
+                        "register_event_duration_secs_listener",
+                        registered.append)
+    entered = []
+
+    class CountingAnnotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+
+        def __exit__(self, *exc):
+            entered.append("/" + self.name)
+
+    monkeypatch.setattr(tracer_mod, "TraceAnnotation", CountingAnnotation)
+    plain = _engine()                 # NULL_OBS on a wall clock
+    plain.submit(steps=3)
+    plain.run()
+    assert registered == [] and entered == []
+
+    obs = Observability()
+    replay = _engine(obs=obs, clock=VirtualClock())
+    replay.submit(steps=3)
+    replay.run()
+    assert registered == []
+    assert not any(e["name"] in ("compile", "compile_listener")
+                   for e in obs.tracer.events())
+    # the bridge itself: every span entered, then exited innermost first
+    assert entered[:4] == ["tick", "admit", "/admit", "schedule"]
+    depth = []
+    for name in entered:
+        if name.startswith("/"):
+            assert depth.pop() == name[1:]
+        else:
+            depth.append(name)
+    assert depth == []
+
+
+def test_span_names_reach_the_profilers_host_plane(tmp_path):
+    obs = Observability()
+    eng = _engine(obs=obs, bank=_multi_segment_bank(), max_batch=2)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for i in range(3):
+            eng.submit(steps=3, seed=i)
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+        obs.close()
+    (path,) = Path(tmp_path).rglob("*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    host = {e.name for pl in data.planes if pl.name == "/host:CPU"
+            for ln in pl.lines for e in ln.events}
+    assert {"tick", "advance", "batch", "dispatch"} <= host
